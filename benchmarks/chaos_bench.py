@@ -25,6 +25,7 @@ import argparse
 import json
 import sys
 
+from repro.launch import compile_cache
 from repro.obs import log
 
 # fault intensity grid: per-attempt loss / corruption probability, mean
@@ -125,10 +126,11 @@ def equivalence_gate(task, *, num_devices: int = 4, rounds: int = 4,
 
 def run_bench(smoke: bool = False, seed: int = 0, tracer=None,
               metrics=None) -> dict:
+    from repro.launch.mesh import device_info
     from repro.models.small import make_task
     task = make_task("mlp_micro", num_samples=2000, test_samples=200,
                      batch_size=32, seed=seed)
-    report = {"bench": "chaos_resilience_sweep", "backend": "cpu",
+    report = {"bench": "chaos_resilience_sweep", "device": device_info(),
               "sanitizer": "nonfinite guard + tau_max=10",
               "fault_levels": FAULT_LEVELS}
     if smoke:
@@ -188,6 +190,7 @@ def main(argv=None) -> int:
                     help="suppress status lines (JSON report still printed)")
     args = ap.parse_args(argv)
     log.set_quiet(args.quiet)
+    compile_cache.enable()
 
     tracer = metrics = None
     if args.trace_out:

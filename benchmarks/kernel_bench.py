@@ -1,14 +1,16 @@
-"""Kernel microbenchmarks (interpret mode on CPU — relative numbers only;
-the BlockSpec tiling targets TPU VMEM). Compares the Pallas pipeline with
-the pure-jnp oracle and the exact lax.top_k path.
+"""Kernel microbenchmarks: the Pallas pipeline against the pure-jnp oracle
+and the exact lax.top_k path. Kernels compile for a TPU and run in the
+Pallas interpreter on the CPU backend, where the timings say nothing about
+the chip.
 
 The pod-sync section measures the compact (values, indices, count) wire
 format of `dist.collectives.make_pod_sync` across the density crossover on
 an 8-device host mesh (P=4 pods × 2 shards): per-device bytes-on-wire from
 the *actual payload arrays*, the analytic `all_gather_bytes` model, the
-dense-carrier cost, and wall time per sync for both paths — plus the
+dense-carrier cost and the CPU wall time per sync of both paths — plus the
 compact-vs-reference equivalence gate (fp32 params, bitwise EF residuals).
-Run directly (device count is forced before jax imports):
+It counts bytes, so it runs on the CPU backend only: `use_host_mesh`
+pins the CPU platform and the host device count before a backend starts.
 
   PYTHONPATH=src python benchmarks/kernel_bench.py --smoke
   PYTHONPATH=src python benchmarks/kernel_bench.py --out BENCH_podsync.json
@@ -18,7 +20,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -41,23 +42,23 @@ def kernel_microbench():
     from repro.core.compression import topk
     from repro.kernels import ops, ref
 
+    backend = jax.default_backend()
     rows = []
     rng = np.random.RandomState(0)
     for d in (1 << 16, 1 << 20):
         g = jnp.asarray(rng.randn(d).astype(np.float32))
         res = jnp.zeros(d)
 
-        us = _time(lambda g, r: ops.topk_compress(g, r, rate=0.01,
-                                                  interpret=True), g, res)
-        rows.append((f"pallas_topk_compress_d{d}", us, "interpret"))
+        us = _time(lambda g, r: ops.topk_compress(g, r, rate=0.01), g, res)
+        rows.append((f"pallas_topk_compress_d{d}", us, backend))
 
         exact = jax.jit(lambda g: topk(g, 0.01).dense())
         rows.append((f"exact_lax_topk_d{d}", _time(exact, g), "oracle"))
 
         mu = jnp.zeros(d)
         us = _time(lambda w, m, gg: ops.momentum_update(
-            w, m, gg, lr=0.01, interpret=True), g, mu, g)
-        rows.append((f"pallas_fused_momentum_d{d}", us, "interpret"))
+            w, m, gg, lr=0.01), g, mu, g)
+        rows.append((f"pallas_fused_momentum_d{d}", us, backend))
 
         unfused = jax.jit(lambda w, m, gg: ref.ref_fused_momentum(
             w, m, gg, lr=0.01))
@@ -88,8 +89,8 @@ def run_podsync(smoke: bool = False) -> tuple[dict, list[str]]:
     """
     import jax
     import jax.numpy as jnp
-    import repro  # noqa: F401  (installs the jax compat shims)
     from repro.dist import collectives as col
+    from repro.launch.mesh import device_info
     from repro.kernels import ops
 
     n_pods, n_data, n_model = 4, 2, 1
@@ -188,7 +189,7 @@ def run_podsync(smoke: bool = False) -> tuple[dict, list[str]]:
     report = {
         "bench": "podsync_wire_bytes",
         "mode": "smoke" if smoke else "full",
-        "backend": jax.default_backend(),
+        "device": device_info(),
         "mesh": {"pod": n_pods, "data": n_data, "model": n_model},
         "dim": dim, "n_blocks": nb, "blk": blk,
         "dim_per_shard": dim_local,
@@ -205,15 +206,22 @@ def run_podsync(smoke: bool = False) -> tuple[dict, list[str]]:
     return report, failures
 
 
+def use_host_mesh() -> None:
+    """Put the pod mesh on 8 host devices of the CPU backend. Call before
+    JAX initializes a backend."""
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
+
+
 def podsync_rows():
-    """CSV rows for benchmarks.run: re-executes this file in a subprocess
-    (the pod mesh needs XLA_FLAGS set before jax initializes)."""
-    out = subprocess.run(
-        [sys.executable, os.path.abspath(__file__), "--smoke", "--quiet"],
-        capture_output=True, text=True, timeout=900)
-    if out.returncode != 0:
-        raise RuntimeError(f"podsync smoke failed:\n{out.stderr[-2000:]}")
-    rep = json.loads(out.stdout)
+    """CSV rows for benchmarks.run, whose `--one podsync` child has not
+    initialized a backend yet."""
+    use_host_mesh()
+    rep, failures = run_podsync(smoke=True)
+    if failures:
+        raise RuntimeError("podsync smoke failed: " + "; ".join(failures))
     rows = []
     for c in rep["cells"]:
         rows.append((f"podsync_bytes_delta{c['rate']:g}",
@@ -229,21 +237,18 @@ def main(argv=None) -> int:
                     help="tiny dim / two rates (CI smoke job)")
     ap.add_argument("--out", default="",
                     help="write the JSON report here (default: stdout only)")
-    ap.add_argument("--quiet", action="store_true",
-                    help="print only the JSON report (podsync_rows parsing)")
     args = ap.parse_args(argv)
 
-    # the pod mesh needs 8 host devices; must be set before jax imports
-    os.environ.setdefault("XLA_FLAGS",
-                          "--xla_force_host_platform_device_count=8")
+    use_host_mesh()
+    from repro.launch import compile_cache
+    compile_cache.enable()
     report, failures = run_podsync(smoke=args.smoke)
     text = json.dumps(report, indent=1)
     print(text)
     if args.out:
         with open(args.out, "w") as f:
             f.write(text + "\n")
-        if not args.quiet:
-            print(f"[kernel_bench] wrote {args.out}", file=sys.stderr)
+        print(f"[kernel_bench] wrote {args.out}", file=sys.stderr)
     if failures:
         print("[kernel_bench] podsync FAIL:\n  " + "\n  ".join(failures),
               file=sys.stderr)

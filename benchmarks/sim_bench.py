@@ -32,6 +32,7 @@ import json
 import sys
 import time
 
+from repro.launch import compile_cache
 from repro.obs import log
 
 # plan-time k grid: collapses the number of distinct compiled local-round
@@ -113,12 +114,13 @@ def _pair(num_devices: int, rounds: int, *, task, ef: bool = False,
 
 
 def run_bench(smoke: bool = False, seed: int = 0, prefetch: int = 0) -> dict:
+    from repro.launch.mesh import device_info
     from repro.models.small import make_task
 
     micro = make_task("mlp_micro", num_samples=2000, test_samples=200,
                       batch_size=32, seed=seed)
     report = {"bench": "simulator_events_per_sec",
-              "strategy": "periodic (FedLuck plans)", "backend": "cpu",
+              "strategy": "periodic (FedLuck plans)", "device": device_info(),
               "unit": "simulated events/sec; wall seconds per sim second",
               "methodology": "steady-state: jit warmup excluded (warm_s)",
               "prefetch": prefetch}
@@ -305,6 +307,7 @@ def main(argv=None) -> int:
                     help="suppress status lines (JSON report still printed)")
     args = ap.parse_args(argv)
     log.set_quiet(args.quiet)
+    compile_cache.enable()
 
     report = run_bench(smoke=args.smoke, seed=args.seed,
                        prefetch=args.prefetch)

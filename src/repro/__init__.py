@@ -1,10 +1,1 @@
-"""FedLuck reproduction: joint local-updating + gradient-compression AFL.
-
-Importing any `repro.*` module first installs the jax back-compat shims
-(`repro._compat`) so the sharding-era API surface the code is written
-against (`AxisType`, `make_mesh(axis_types=)`, `set_mesh`, `shard_map`)
-exists on the pinned jax 0.4.37 toolchain.
-"""
-from repro import _compat
-
-_compat.install()
+"""FedLuck reproduction: joint local-updating + gradient-compression AFL."""

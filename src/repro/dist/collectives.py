@@ -31,13 +31,16 @@ collective is a `shard_map` all-gather of ONLY these payloads over the
 δ, not with d.
 
 Above the crossover a dense ring all-reduce is cheaper and the compression
-only serves the EF contract; that path keeps the exact global threshold
-pipeline (`kernels.ops.topk_compress`, vmapped over pods).
+only serves the EF contract; that path keeps the exact per-pod threshold
+of `kernels.ops.topk_compress`: each chip histograms its own shard, the
+in-pod shards sum their counts, and each selects its shard against the
+whole pod's threshold (`ef_topk`) and psums the dense carrier over `pod`.
 
 `make_pod_sync(..., wire=...)` picks the path: "auto" dispatches at build
 time on `density_crossover`, "compact"/"dense" force one, and "reference"
 is the dense-carrier oracle of the compact selection semantics (same
-thresholds and budgets, GSPMD mean instead of the sparse gather) that the
+thresholds and budgets, a dense psum over pods instead of the sparse
+gather, and `kernels.ref` instead of the pack kernel) that the
 equivalence tests and the `podsync` benchmark gate diff against.
 
 `CompactWire` / `all_gather_bytes` / `density_crossover` are the wire-cost
@@ -55,6 +58,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops, ref
+from repro.kernels.ef_topk import ef_topk
 
 VALUE_BYTES = 4    # fp32 payload
 INDEX_BYTES = 4    # int32 shard-local flat coordinate
@@ -148,23 +152,29 @@ def make_pod_sync(mesh, dim: int, *, rate: float, eta_g: float = 1.0,
     if wire not in ("compact", "dense", "reference"):
         raise ValueError(f"unknown wire mode {wire!r}")
 
+    if n_blocks % n_shards != 0:
+        raise ValueError(f"n_blocks={n_blocks} not divisible by the "
+                         f"in-pod shard count {n_shards}")
+    nbl = n_blocks // n_shards          # blocks each chip owns
     budget = block_budget(blk, rate)
     if wire in ("compact", "reference"):
-        if n_blocks % n_shards != 0:
-            raise ValueError(f"n_blocks={n_blocks} not divisible by the "
-                             f"in-pod shard count {n_shards}")
-        nbl = n_blocks // n_shards      # blocks each chip owns
         k_shard = nbl * budget          # shard threshold target
         wire_fmt = CompactWire(nbl, blk, budget)
     else:
         wire_fmt = None
 
-    if wire == "compact":
-        inpod_entry = inpod if inpod else None
-        pspec = jax.sharding.PartitionSpec(inpod_entry, None)
-        dspec = jax.sharding.PartitionSpec("pod" if has_pod else None,
-                                           inpod_entry, None)
+    inpod_entry = inpod if inpod else None
+    pod_entry = "pod" if has_pod else None
+    pspec = jax.sharding.PartitionSpec(inpod_entry, None)
+    dspec = jax.sharding.PartitionSpec(pod_entry, inpod_entry, None)
 
+    def pod_sum(x):
+        return jax.lax.psum(x, "pod") if has_pod else x
+
+    # Every path runs its kernels inside a shard_map: a Pallas call is a
+    # custom call that the SPMD partitioner cannot split, so under plain
+    # GSPMD it would gather every pod's state onto every chip.
+    if wire == "compact":
         def shard_fn(p_l, d_l, r_l):
             with jax.named_scope("pod_sync.compact_pack"):
                 acc = d_l[0].astype(jnp.float32) + r_l[0].astype(jnp.float32)
@@ -183,48 +193,43 @@ def make_pod_sync(mesh, dim: int, *, rate: float, eta_g: float = 1.0,
                     .astype(p_l.dtype)
             return new_p, res[None].astype(r_l.dtype)
 
-        mapped = jax.shard_map(shard_fn, mesh=mesh,
-                               in_specs=(pspec, dspec, dspec),
-                               out_specs=(pspec, dspec), check_vma=False)
-
-        def sync(params, deltas, residuals):
-            return mapped(params, deltas, residuals)
+        in_specs, out_specs = (pspec, dspec, dspec), (pspec, dspec)
 
     elif wire == "reference":
-        def sync(params, deltas, residuals):
-            acc = deltas.astype(jnp.float32) + residuals.astype(jnp.float32)
-            accs = acc.reshape(n_pods, n_shards, nbl, blk)
+        def shard_fn(p_l, d_l, r_l):
+            acc = d_l[0].astype(jnp.float32) + r_l[0].astype(jnp.float32)
+            t = ops.solve_threshold(acc.reshape(-1), k_shard,
+                                    interpret=interpret)
+            _, _, _, res = ref.ref_compact_blocks(acc, t, budget)
+            kept = acc - res                 # shipped selection, dense carrier
+            update = pod_sum(kept) / n_pods  # Eq. 6 reduce
+            return p_l - eta_g * update, res[None]
 
-            def one_shard(a):
-                t = ops.solve_threshold(a.reshape(-1), k_shard,
-                                        interpret=interpret)
-                _, _, _, res = ref.ref_compact_blocks(a, t, budget)
-                return a - res   # shipped selection, dense carrier
+        in_specs, out_specs = (pspec, dspec, dspec), (pspec, dspec)
 
-            kept = jax.vmap(jax.vmap(one_shard))(accs) \
-                .reshape(n_pods, n_blocks, blk)
-            new_residuals = acc - kept
-            update = jnp.mean(kept, axis=0)          # Eq. 6 reduce
-            return params - eta_g * update, new_residuals
+    else:  # dense ring: exact per-pod threshold, dense cross-pod all-reduce
+        k_pod = max(1, min(dim, round(rate * dim)))
 
-    else:  # dense ring: exact global threshold, dense GSPMD mean
-        def compress_dense(acc_p, res_p):
-            kw = {} if interpret is None else {"interpret": interpret}
-            out, _, _, _ = ops.topk_compress(
-                (acc_p - res_p).reshape(dim), res_p.reshape(dim), rate=rate,
-                **kw)
-            return out.reshape(n_blocks, blk)
-
-        def sync(params, deltas, residuals):
+        def shard_fn(p_l, d_l, r_l):
             with jax.named_scope("pod_sync.dense"):
-                acc = deltas.astype(jnp.float32) \
-                    + residuals.astype(jnp.float32)
-                kept = jax.vmap(compress_dense)(
-                    acc, residuals.astype(jnp.float32))
-                new_residuals = acc - kept
-                update = jnp.mean(kept, axis=0)      # Eq. 6 cross-pod reduce
-                return params - eta_g * update, new_residuals
+                g = d_l[0].astype(jnp.float32).reshape(-1)
+                res = r_l[0].astype(jnp.float32).reshape(-1)
+                # the whole pod's threshold: in-pod shards reduce their
+                # max and histogram counts, then each selects its own part
+                t = ops.solve_threshold(g + res, k_pod,
+                                        axis_name=inpod or None,
+                                        interpret=interpret)
+                out, res, _ = ef_topk(g, res, t,
+                                      interpret=ops.resolve_interpret(
+                                          interpret))
+                kept = out.reshape(p_l.shape)
+                update = pod_sum(kept) / n_pods      # Eq. 6 cross-pod reduce
+                return p_l - eta_g * update, res.reshape(r_l.shape)
 
+        in_specs, out_specs = (pspec, dspec, dspec), (pspec, dspec)
+
+    sync = jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
     sync.path = wire
     sync.wire = wire_fmt
     if wire_fmt is not None:

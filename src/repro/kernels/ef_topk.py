@@ -20,24 +20,23 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import tiling
+
 DEFAULT_BLOCK = 8 * 1024
 
 
 def _ef_topk_kernel(g_ref, r_ref, t_ref, out_ref, res_ref, nnz_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         nnz_ref[...] = jnp.zeros_like(nnz_ref)
 
     acc = g_ref[...].astype(jnp.float32) + r_ref[...].astype(jnp.float32)
-    t = t_ref[0, 0]
-    keep = (jnp.abs(acc) >= t)
+    keep = jnp.abs(acc) >= t_ref[0]
     kept = jnp.where(keep, acc, 0.0)
     out_ref[...] = kept.astype(out_ref.dtype)
     res_ref[...] = (acc - kept).astype(res_ref.dtype)
-    nnz_ref[...] += jnp.sum(keep.astype(jnp.float32), keepdims=True
-                            ).reshape(nnz_ref.shape)
+    per_row = jnp.sum(keep.astype(jnp.float32), axis=1, keepdims=True)
+    nnz_ref[...] += jnp.sum(per_row, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -45,33 +44,21 @@ def ef_topk(g: jax.Array, residual: jax.Array, threshold: jax.Array, *,
             block: int = DEFAULT_BLOCK, interpret: bool = False):
     """Returns (out, new_residual, nnz) — flat, same dtype as g."""
     d = g.shape[0]
-    pad = (-d) % block
-    if pad:
-        g = jnp.concatenate([g, jnp.zeros((pad,), g.dtype)])
-        residual = jnp.concatenate([residual, jnp.zeros((pad,), residual.dtype)])
-    nblocks = g.shape[0] // block
-    g2 = g.reshape(nblocks, block)
-    r2 = residual.reshape(nblocks, block)
-    t2 = jnp.asarray(threshold, jnp.float32).reshape(1, 1)
+    g2 = tiling.to_tiles(g, block)
+    r2 = tiling.to_tiles(residual, block)
+    spec = tiling.tile_spec(block)
+    nnz_spec = pl.BlockSpec((1, 1), lambda i: (0, 0))
 
     out, res, nnz = pl.pallas_call(
         _ef_topk_kernel,
-        grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        ],
+        grid=(g2.shape[0] // tiling.ROWS,),
+        in_specs=[spec, spec, tiling.scalar_spec()],
+        out_specs=[spec, spec, nnz_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((nblocks, block), g.dtype),
-            jax.ShapeDtypeStruct((nblocks, block), residual.dtype),
+            jax.ShapeDtypeStruct(g2.shape, g.dtype),
+            jax.ShapeDtypeStruct(r2.shape, residual.dtype),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(g2, r2, t2)
+    )(g2, r2, tiling.scalar(threshold))
     return out.reshape(-1)[:d], res.reshape(-1)[:d], nnz[0, 0]

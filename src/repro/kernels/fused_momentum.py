@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import tiling
+
 DEFAULT_BLOCK = 8 * 1024
 
 
@@ -35,21 +37,16 @@ def fused_momentum(w: jax.Array, mu: jax.Array, g: jax.Array, *,
                    block: int = DEFAULT_BLOCK, interpret: bool = False):
     """Flat [d] update. Returns (w', mu')."""
     d = w.shape[0]
-    pad = (-d) % block
-    if pad:
-        z = lambda x: jnp.concatenate([x, jnp.zeros((pad,), x.dtype)])
-        w, mu, g = z(w), z(mu), z(g)
-    nblocks = w.shape[0] // block
-    shp = (nblocks, block)
-    spec = pl.BlockSpec((1, block), lambda i: (i, 0))
+    w2, mu2, g2 = (tiling.to_tiles(x, block) for x in (w, mu, g))
+    spec = tiling.tile_spec(block)
 
-    w2, mu2 = pl.pallas_call(
+    w_new, mu_new = pl.pallas_call(
         _make_kernel(lr, momentum),
-        grid=(nblocks,),
+        grid=(w2.shape[0] // tiling.ROWS,),
         in_specs=[spec, spec, spec],
         out_specs=[spec, spec],
-        out_shape=[jax.ShapeDtypeStruct(shp, w.dtype),
-                   jax.ShapeDtypeStruct(shp, mu.dtype)],
+        out_shape=[jax.ShapeDtypeStruct(w2.shape, w.dtype),
+                   jax.ShapeDtypeStruct(mu2.shape, mu.dtype)],
         interpret=interpret,
-    )(w.reshape(shp), mu.reshape(shp), g.reshape(shp))
-    return w2.reshape(-1)[:d], mu2.reshape(-1)[:d]
+    )(w2, mu2, g2)
+    return w_new.reshape(-1)[:d], mu_new.reshape(-1)[:d]

@@ -8,8 +8,10 @@
   solve   threshold t s.t. #{|g+r| >= t} ~= δ·d   (O(buckets), on-chip)
   pass 3  fused EF select                     (ef_topk kernel)
 
-On CPU (this container) kernels run with interpret=True; on TPU they
-compile to Mosaic. All wrappers are shape-polymorphic over flat [d] inputs.
+`interpret=None` (the default) is resolved when a wrapper is traced: the
+kernels run in the Pallas interpreter on the CPU backend and compile to
+Mosaic on TPU. Importing this module never starts a backend. All wrappers
+are shape-polymorphic over flat [d] inputs.
 """
 from __future__ import annotations
 
@@ -23,7 +25,12 @@ from repro.kernels.ef_topk import ef_topk
 from repro.kernels.fused_momentum import fused_momentum
 from repro.kernels.magnitude_hist import magnitude_hist
 
-INTERPRET = jax.default_backend() == "cpu"
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """`interpret` as given, else True exactly on the CPU backend."""
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    return interpret
 
 
 def _solve_threshold(counts_ge: jax.Array, edges: jax.Array, k) -> tuple:
@@ -40,31 +47,41 @@ def _solve_threshold(counts_ge: jax.Array, edges: jax.Array, k) -> tuple:
 
 @functools.partial(jax.jit,
                    static_argnames=("coarse_buckets", "fine_buckets",
-                                    "block", "interpret"))
+                                    "block", "interpret", "axis_name"))
 def solve_threshold(acc: jax.Array, k, *, coarse_buckets: int = 48,
                     fine_buckets: int = 128, block: int = 8 * 1024,
-                    interpret: bool | None = None) -> jax.Array:
+                    interpret: bool | None = None,
+                    axis_name=None) -> jax.Array:
     """Histogram-pipeline threshold t with #{|acc| >= t} ≈ k (passes 0–2 of
     the top-k pipeline; `k` may be traced). Shared by `topk_compress` and
     the pod-sync compact path, so both select against identical thresholds.
+
+    With `axis_name` (inside `shard_map`), `acc` is this chip's shard of a
+    vector split over those mesh axes, and t is the whole vector's
+    threshold: the max and the histogram counts are reduced over the axes.
     """
-    if interpret is None:
-        interpret = INTERPRET
-    gmax = jnp.max(jnp.abs(acc)) + 1e-30
+    interpret = resolve_interpret(interpret)
+    gmax = jnp.max(jnp.abs(acc))
+
+    def hist(edges):
+        counts = magnitude_hist(acc, edges, block=block, interpret=interpret)
+        return counts if axis_name is None else \
+            jax.lax.psum(counts, axis_name)
+
+    if axis_name is not None:
+        gmax = jax.lax.pmax(gmax, axis_name)
+    gmax = gmax + 1e-30
 
     # pass 1: coarse log2 buckets
     coarse_edges = gmax * 2.0 ** (-jnp.arange(coarse_buckets + 1,
                                               dtype=jnp.float32))
-    c_counts = magnitude_hist(acc, coarse_edges, block=block,
-                              interpret=interpret)
-    lo, hi = _solve_threshold(c_counts, coarse_edges, k)
+    lo, hi = _solve_threshold(hist(coarse_edges), coarse_edges, k)
 
     # pass 2: fine linear buckets inside [lo, hi]
     frac = jnp.arange(fine_buckets + 1, dtype=jnp.float32) / fine_buckets
     fine_edges = hi - (hi - lo) * frac         # descending hi -> lo
     fine_edges = jnp.maximum(fine_edges, 1e-30)
-    f_counts = magnitude_hist(acc, fine_edges, block=block,
-                              interpret=interpret)
+    f_counts = hist(fine_edges)
     _, t = _solve_threshold(f_counts, fine_edges, k)
     return t
 
@@ -81,8 +98,7 @@ def topk_compress(g: jax.Array, residual: jax.Array, *, rate: float,
     exact top-|.|-k up to threshold-resolution ties: nnz ∈ [~k, k(1+ε)]
     with ε bounded by the fine bucket width (tested in test_kernels).
     """
-    if interpret is None:
-        interpret = INTERPRET
+    interpret = resolve_interpret(interpret)
     d = g.shape[0]
     k = max(1, min(d, int(round(rate * d))))
     # NOTE: threshold statistics must be over the EF accumulator, since
@@ -150,8 +166,7 @@ def compact_shard_topk(acc: jax.Array, *, budget: int,
     indices [nb, budget] i32 shard-local flat, counts [nb] i32 header,
     residual [nb, blk]) — the pod-sync wire payload plus the EF carry.
     """
-    if interpret is None:
-        interpret = INTERPRET
+    interpret = resolve_interpret(interpret)
     with jax.named_scope("compact_shard_topk"):
         nb, blk = acc.shape
         acc = acc.astype(jnp.float32)
@@ -167,8 +182,7 @@ def compact_shard_topk(acc: jax.Array, *, budget: int,
 def momentum_update(w: jax.Array, mu: jax.Array, g: jax.Array, *, lr: float,
                     momentum: float = 0.9, block: int = 8 * 1024,
                     interpret: bool | None = None):
-    if interpret is None:
-        interpret = INTERPRET
+    interpret = resolve_interpret(interpret)
     with jax.named_scope("fused_momentum"):
         return fused_momentum(w, mu, g, lr=lr, momentum=momentum,
                               block=block, interpret=interpret)

@@ -1,7 +1,10 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# NOTE: the two lines above MUST run before any other import — jax locks the
-# device count at first initialization (see system spec, MULTI-POD DRY-RUN).
+# NOTE: the lines above MUST run before any other import — jax locks the
+# platform and device count at first initialization. The dry-run is a
+# CPU-only tool: 512 host devices stand in for the production mesh, and
+# neither it nor its --all children ever claim an accelerator.
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 

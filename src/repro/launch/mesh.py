@@ -23,6 +23,13 @@ def make_local_mesh(data: int = 1, model: int = 1):
         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
+def device_info() -> dict:
+    """The devices JAX runs on, as every benchmark result names them."""
+    devices = jax.devices()
+    return {"platform": devices[0].platform, "kind": devices[0].device_kind,
+            "count": len(devices)}
+
+
 def batch_axes_for(mesh) -> tuple[str, ...]:
     """Batch shards over pod+data when the pod axis exists."""
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)

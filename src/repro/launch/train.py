@@ -31,6 +31,7 @@ import time
 
 import numpy as np
 
+from repro.launch import compile_cache
 from repro.obs import log
 
 
@@ -82,11 +83,12 @@ def restore_fl_state(sim, state) -> None:
                            np.asarray(state["residuals"]))
 
 
-def run_fl(args) -> dict:
+def make_fl_sim(args, engine: str = "batched"):
+    """Build the AFL simulator (task, fleet plan, fault models, obs) that
+    `run_fl` drives; `engine="sequential"` gives the tests' oracle engine
+    on the same fleet."""
     import jax
-    import jax.numpy as jnp
 
-    from repro.checkpoint import CheckpointManager
     from repro.core import compression as C
     from repro.core.simulator import (AFLSimulator, STRATEGY_FOR_METHOD,
                                       make_heterogeneous_devices, plan_devices)
@@ -129,11 +131,22 @@ def run_fl(args) -> dict:
                                     clip_norm=args.clip_norm)
 
     tracer, metrics = make_obs(args)
-    sim = AFLSimulator(task, specs, STRATEGY_FOR_METHOD[args.method],
-                       round_period=args.round_period, eta_l=args.eta_l,
-                       eta_g=args.eta_g, seed=args.seed, client_indices=idx,
-                       failure_schedule=failure, channel=channel,
-                       sanitizer=sanitizer, tracer=tracer, metrics=metrics)
+    return AFLSimulator(task, specs, STRATEGY_FOR_METHOD[args.method],
+                        round_period=args.round_period, eta_l=args.eta_l,
+                        eta_g=args.eta_g, seed=args.seed, client_indices=idx,
+                        failure_schedule=failure, channel=channel,
+                        sanitizer=sanitizer, tracer=tracer, metrics=metrics,
+                        engine=engine)
+
+
+def run_fl(args, sim=None) -> dict:
+    """Train `args.rounds` rounds on `sim` (default: `make_fl_sim(args)`)
+    in checkpointed segments; returns the final summary."""
+    from repro.checkpoint import CheckpointManager
+
+    if sim is None:
+        sim = make_fl_sim(args)
+    tracer, metrics = sim._tracer, sim._metrics
 
     mgr = CheckpointManager(args.ckpt_dir, max_to_keep=2) \
         if args.ckpt_dir else None
@@ -169,8 +182,9 @@ def run_fl(args) -> dict:
     export_obs(args, tracer, metrics,
                extra={"engine": "batched" if sim._batched else "sequential",
                       "task": args.task, "method": args.method})
-    return {"final_accuracy": final.accuracy, "rounds": sim.model.round,
-            "gbits": final.gbits, "sim_time": final.time,
+    return {"final_accuracy": final.accuracy, "final_loss": final.loss,
+            "rounds": sim.model.round, "gbits": final.gbits,
+            "sim_time": final.time, "events": sim.events_processed,
             "fault_counters": sim.fault_counters()}
 
 
@@ -272,7 +286,7 @@ def run_datacenter(args) -> dict:
     return {"loss": float(np.mean(losses)), "comm_mb": comm_bits / 8e6}
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", default="fl", choices=["fl", "datacenter"])
     # fl
@@ -328,8 +342,13 @@ def main(argv=None):
                          "(repro.obs.MetricsRegistry)")
     ap.add_argument("--quiet", action="store_true",
                     help="suppress status lines (final JSON still printed)")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
+
+
+def main(argv=None):
+    args = parse_args(argv)
     log.set_quiet(args.quiet)
+    compile_cache.enable()
 
     res = run_fl(args) if args.mode == "fl" else run_datacenter(args)
     print(json.dumps(res, indent=1))

@@ -37,11 +37,8 @@ def annotate(name: str):
     profiling is enabled, else a shared no-op context."""
     if not _PROFILE:
         return _NULL_CTX
-    try:
-        import jax
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:               # profiler unavailable on this backend
-        return _NULL_CTX
+    import jax
+    return jax.profiler.TraceAnnotation(name)
 
 
 class PhaseTimers:

@@ -226,6 +226,48 @@ class TestShardedTraining:
         print("OK")
         """)
 
+    def test_pod_sync_dense_shards_match_whole_pod_pipeline(self):
+        """The dense path splits each pod's vector over its 4 in-pod chips
+        but selects against the whole pod's threshold: bitwise the EF
+        residuals of `topk_compress` on each pod's whole vector, over 3
+        rounds; and its wire model charges each chip's shard only."""
+        _run("""
+        from repro.dist import collectives as col
+        from repro.kernels import ops
+        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,)*3)
+        nb, blk = 8, 128
+        dim = nb * blk
+        rng = np.random.RandomState(2)
+        params = jnp.asarray(rng.randn(nb, blk).astype(np.float32))
+        for rate in (0.6, 0.1):
+            sync = col.make_pod_sync(mesh, dim, rate=rate, n_blocks=nb,
+                                     wire="dense")
+            assert sync.path == "dense"
+            assert sync.bytes_per_device == 2.0 * (2 - 1) / 2 * dim / 4 * 4
+            jd = jax.jit(sync)
+            p, r = params, jnp.zeros((2, nb, blk), jnp.float32)
+            p_ref, r_ref = np.asarray(params), np.zeros((2, dim), np.float32)
+            for rnd in range(3):
+                d = rng.randn(2, nb, blk).astype(np.float32)
+                with mesh:
+                    p, r = jd(p, jnp.asarray(d), r)
+                kept = []
+                for i in range(2):
+                    out, res, _, _ = ops.topk_compress(
+                        jnp.asarray(d[i].reshape(dim)),
+                        jnp.asarray(r_ref[i]), rate=rate)
+                    kept.append(np.asarray(out))
+                    r_ref[i] = np.asarray(res)
+                p_ref = p_ref - (kept[0] + kept[1]).reshape(nb, blk) / 2
+                assert np.array_equal(np.asarray(r).reshape(2, dim),
+                                      r_ref), (rate, rnd)
+                assert np.allclose(np.asarray(p), p_ref,
+                                   rtol=1e-5, atol=1e-6), (rate, rnd)
+            assert float(np.abs(r_ref).max()) > 0
+        print("OK")
+        """)
+
     def test_pod_round_step_composes_local_rounds_and_sync(self):
         """make_pod_round_step == (vmapped local rounds) ∘ make_pod_sync,
         and its static wire-bit charge is the sync's compact payload."""

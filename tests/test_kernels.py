@@ -1,5 +1,6 @@
-"""Per-kernel shape/dtype sweeps, assert_allclose against the ref.py oracles
-(kernels run in interpret mode on CPU; same code compiles to Mosaic on TPU)."""
+"""Per-kernel shape/dtype sweeps, assert_allclose against the ref.py oracles.
+Kernels run in the Pallas interpreter here; tests/test_tpu_compile.py
+compiles the same code for a TPU v5e and chip_smoke.py runs it on one."""
 import jax
 import jax.numpy as jnp
 import numpy as np
