@@ -103,7 +103,8 @@ def make_local_round_step(lm, opt, k: int):
         def body(carry, batch):
             p, s = carry
             loss, grads = jax.value_and_grad(lm.loss)(p, batch)
-            p, s = opt.update(grads, s, p)
+            with jax.named_scope("opt.update"):
+                p, s = opt.update(grads, s, p)
             return (p, s), loss
 
         with jax.named_scope("local_round"):
@@ -112,7 +113,7 @@ def make_local_round_step(lm, opt, k: int):
             delta = jax.tree.map(
                 lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32),
                 params, p_k)
-        return p_k, s_k, delta, jnp.mean(losses)
+            return p_k, s_k, delta, jnp.mean(losses)
 
     return round_fn
 
@@ -141,21 +142,27 @@ def make_pod_round_step(lm, opt, k: int, sync, *, spec, dim: int,
 
     def step(params_blocked, opt_states, batches, residuals):
         nb, blk = params_blocked.shape
-        params = C.unflatten_pytree(params_blocked.reshape(-1)[:dim], spec)
+        with jax.named_scope("pod_round.unflatten"):
+            params = C.unflatten_pytree(params_blocked.reshape(-1)[:dim],
+                                        spec)
 
         def one_pod(opt_state, pod_batches):
             _, s_k, delta, loss = local(params, opt_state, pod_batches)
-            flat_delta, _ = C.flatten_pytree(delta)
+            with jax.named_scope("pod_round.flatten"):
+                flat_delta, _ = C.flatten_pytree(delta)
             return s_k, flat_delta, loss
 
         new_states, flat_deltas, losses = jax.vmap(one_pod)(opt_states,
                                                             batches)
-        pad = nb * blk - dim
-        if pad:
-            flat_deltas = jnp.pad(flat_deltas, ((0, 0), (0, pad)))
-        deltas = flat_deltas.reshape(-1, nb, blk)
+        with jax.named_scope("pod_round.flatten"):
+            pad = nb * blk - dim
+            if pad:
+                flat_deltas = jnp.pad(flat_deltas, ((0, 0), (0, pad)))
+            deltas = flat_deltas.reshape(-1, nb, blk)
         new_blocked, new_residuals = sync(params_blocked, deltas, residuals)
-        return new_blocked, new_states, new_residuals, jnp.mean(losses)
+        with jax.named_scope("pod_round.flatten"):
+            loss = jnp.mean(losses)
+        return new_blocked, new_states, new_residuals, loss
 
     step.wire_bits_per_pod = float(getattr(sync, "payload_bits_per_pod",
                                            0.0))

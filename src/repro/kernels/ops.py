@@ -61,28 +61,28 @@ def solve_threshold(acc: jax.Array, k, *, coarse_buckets: int = 48,
     threshold: the max and the histogram counts are reduced over the axes.
     """
     interpret = resolve_interpret(interpret)
-    gmax = jnp.max(jnp.abs(acc))
 
     def hist(edges):
         counts = magnitude_hist(acc, edges, block=block, interpret=interpret)
         return counts if axis_name is None else \
             jax.lax.psum(counts, axis_name)
 
-    if axis_name is not None:
-        gmax = jax.lax.pmax(gmax, axis_name)
-    gmax = gmax + 1e-30
+    with jax.named_scope("topk.threshold"):
+        gmax = jnp.max(jnp.abs(acc))
+        if axis_name is not None:
+            gmax = jax.lax.pmax(gmax, axis_name)
+        gmax = gmax + 1e-30
 
-    # pass 1: coarse log2 buckets
-    coarse_edges = gmax * 2.0 ** (-jnp.arange(coarse_buckets + 1,
-                                              dtype=jnp.float32))
-    lo, hi = _solve_threshold(hist(coarse_edges), coarse_edges, k)
+        # pass 1: coarse log2 buckets
+        coarse_edges = gmax * 2.0 ** (-jnp.arange(coarse_buckets + 1,
+                                                  dtype=jnp.float32))
+        lo, hi = _solve_threshold(hist(coarse_edges), coarse_edges, k)
 
-    # pass 2: fine linear buckets inside [lo, hi]
-    frac = jnp.arange(fine_buckets + 1, dtype=jnp.float32) / fine_buckets
-    fine_edges = hi - (hi - lo) * frac         # descending hi -> lo
-    fine_edges = jnp.maximum(fine_edges, 1e-30)
-    f_counts = hist(fine_edges)
-    _, t = _solve_threshold(f_counts, fine_edges, k)
+        # pass 2: fine linear buckets inside [lo, hi]
+        frac = jnp.arange(fine_buckets + 1, dtype=jnp.float32) / fine_buckets
+        fine_edges = hi - (hi - lo) * frac         # descending hi -> lo
+        fine_edges = jnp.maximum(fine_edges, 1e-30)
+        _, t = _solve_threshold(hist(fine_edges), fine_edges, k)
     return t
 
 
@@ -174,7 +174,8 @@ def compact_shard_topk(acc: jax.Array, *, budget: int,
                             coarse_buckets=coarse_buckets,
                             fine_buckets=fine_buckets, block=block,
                             interpret=interpret)
-        return compact_blocks(acc, t, budget=budget, interpret=interpret)
+        with jax.named_scope("topk.pack"):
+            return compact_blocks(acc, t, budget=budget, interpret=interpret)
 
 
 @functools.partial(jax.jit,

@@ -21,8 +21,8 @@ Four small pieces, composable and individually optional:
   profiling  `PhaseTimers` (perf_counter wall-clock phase accumulators for
              heap-drain / bucket dispatch / host aggregation) and
              `annotate()` — an optional `jax.profiler` trace-annotation
-             context around the pod-sync / compact-topk / fused-momentum
-             dispatches, enabled via `set_profiling(True)` or
+             context around the simulator's bucket dispatch
+             (`sim.bucket_dispatch`), enabled via `set_profiling(True)` or
              REPRO_PROFILE=1.
   log        stdout-safe status lines: progress text goes to stderr (and a
              `--quiet` flag silences it), so benchmark JSON on stdout is
@@ -38,15 +38,14 @@ from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                STALENESS_BUCKETS)
 from repro.obs.perfetto import (PerfettoExporter, validate_chrome_trace,
                                 validate_metrics_json)
-from repro.obs.profiling import (PhaseTimers, annotate, profiling_enabled,
-                                 set_profiling)
+from repro.obs.profiling import PhaseTimers, annotate, set_profiling
 from repro.obs.trace import (NULL_TRACER, NullTracer, TraceEvent, Tracer,
                              CONTROLLER_TRACK, SERVER_TRACK, device_track)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "STALENESS_BUCKETS",
     "PerfettoExporter", "validate_chrome_trace", "validate_metrics_json",
-    "PhaseTimers", "annotate", "profiling_enabled", "set_profiling",
+    "PhaseTimers", "annotate", "set_profiling",
     "NULL_TRACER", "NullTracer", "TraceEvent", "Tracer",
     "CONTROLLER_TRACK", "SERVER_TRACK", "device_track", "log",
 ]

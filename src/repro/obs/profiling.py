@@ -8,9 +8,11 @@ host-side, outside jit, so they never touch traced code.
 `annotate(name)` wraps a host-side dispatch in a `jax.profiler`
 TraceAnnotation when profiling is switched on (`set_profiling(True)` or
 REPRO_PROFILE=1 in the environment), so `jax.profiler.trace()` captures
-show the pod-sync / compact-topk / fused-momentum dispatches as named
-regions. When profiling is off it returns a shared null context — one
-module-level predicate per call, no allocation.
+show it as a named host span: the simulator's `sim.bucket_dispatch`, to
+which the benchmark's trace reduction puts the device's idle gaps down.
+When profiling is off it returns a shared null context — one module-level
+predicate per call, no allocation. The device's own layers are
+`jax.named_scope`s in the jitted code, not annotations.
 """
 from __future__ import annotations
 
@@ -26,10 +28,6 @@ def set_profiling(on: bool) -> None:
     """Globally enable/disable jax.profiler trace annotations."""
     global _PROFILE
     _PROFILE = bool(on)
-
-
-def profiling_enabled() -> bool:
-    return _PROFILE
 
 
 def annotate(name: str):
