@@ -22,13 +22,17 @@ Every chip thresholds only the blocks it owns: one histogram threshold
 solve per shard (`kernels.ops.compact_shard_topk`) targeting
 `budget · n_owned_blocks` keeps, then the `compact_topk` Pallas kernel
 packs each block's survivors into the fixed budget. Padding slots carry
-(0.0, 0) — scatter-adding them is a no-op — so
-`zeros.at[indices].add(values)` reconstructs the selection exactly, and
-blocks whose survivors overflow the budget defer the excess to the next
-round through the EF residual (`residual' = acc − shipped`, bitwise). The
-collective is a `shard_map` all-gather of ONLY these payloads over the
-`pod` axis followed by a local scatter-accumulate: wire bytes scale with
-δ, not with d.
+(0.0, 0) — adding them is a no-op — so the payload reconstructs the
+selection exactly, and blocks whose survivors overflow the budget defer
+the excess to the next round through the EF residual
+(`residual' = acc − shipped`, bitwise). The collective is a `shard_map`
+all-gather of ONLY these payloads over the `pod` axis: wire bytes scale
+with δ, not with d. Each chip then applies the gathered payloads of every
+pod in the `compact_topk.expand_blocks` Pallas kernel, the inverse of the
+pack: block b's slots sit in row b and point into block b, so each block
+is rebuilt from its own P · budget slots (summed in pod order, for one
+pod bitwise `zeros.at[indices].add(values)`), with no global sort or
+scatter, and written as `w − η_g · sum / P` in one pass over w.
 
 Above the crossover a dense ring all-reduce is cheaper and the compression
 only serves the EF contract; that path keeps the exact per-pod threshold
@@ -58,6 +62,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops, ref
+from repro.kernels.compact_topk import expand_blocks
 from repro.kernels.ef_topk import ef_topk
 
 VALUE_BYTES = 4    # fp32 payload
@@ -187,10 +192,9 @@ def make_pod_sync(mesh, dim: int, *, rate: float, eta_g: float = 1.0,
                 else:
                     vals, idx = vals[None], idx[None]
             with jax.named_scope("pod_sync.scatter_apply"):
-                upd = jnp.zeros((acc.size,), jnp.float32).at[
-                    idx.reshape(-1)].add(vals.reshape(-1)) / n_pods
-                new_p = (p_l - eta_g * upd.reshape(acc.shape)) \
-                    .astype(p_l.dtype)
+                new_p = expand_blocks(
+                    p_l, vals, idx, eta_g=eta_g, n_pods=n_pods,
+                    interpret=ops.resolve_interpret(interpret))
             return new_p, res[None].astype(r_l.dtype)
 
         in_specs, out_specs = (pspec, dspec, dspec), (pspec, dspec)

@@ -65,3 +65,14 @@ def ref_compact_blocks(acc: jax.Array, threshold, budget: int) -> tuple:
         + (jnp.arange(n_blocks, dtype=jnp.int32) * blk)[:, None]
     idx = jnp.where(slot_live, gidx, 0)
     return vals, idx, cnt, acc - shipped
+
+
+def ref_expand_blocks(p: jax.Array, values: jax.Array, indices: jax.Array,
+                      eta_g, n_pods) -> jax.Array:
+    """Oracle for kernels.compact_topk.expand_blocks: every pod's payload
+    (values / indices [P, n_blocks, budget], shard-flat indices) scatter-
+    added onto zeros, and p − eta_g · (that sum / n_pods) in p's dtype."""
+    dense = jnp.zeros((p.size,), jnp.float32).at[indices.reshape(-1)].add(
+        values.reshape(-1).astype(jnp.float32))
+    return (p.astype(jnp.float32)
+            - eta_g * (dense.reshape(p.shape) / n_pods)).astype(p.dtype)

@@ -200,6 +200,86 @@ class TestCompactBlocks:
         assert float(t) == float(t_pipe)
 
 
+# (n_blocks, blk, budget): three grid steps of 128 blocks, the last one
+# partial; one partial step over two chunks of offsets; the budget filling
+# the whole block
+EXPAND_SHAPES = [(300, 128, 5), (13, 256, 32), (5, 64, 64)]
+
+
+class TestExpandBlocks:
+    """compact_topk.expand_blocks — the compact sync's apply of the gathered
+    payload, the inverse of the pack."""
+
+    def _payload(self, n_pods, nb, blk, budget, seed):
+        """Each pod's pack of its own accumulator, every third block of it
+        empty (count 0); block 0 keeps fewer than `budget`, so it carries
+        (0.0, 0) padding slots. Returns values, indices [P, nb, budget]
+        and the shipped selections acc − residual [P, nb, blk]."""
+        from repro.kernels.compact_topk import compact_blocks
+        rng = np.random.RandomState(seed)
+        vals, idx, shipped = [], [], []
+        for _ in range(n_pods):
+            acc = rng.randn(nb, blk).astype(np.float32) \
+                * np.exp(rng.randn(nb, blk).astype(np.float32))
+            acc[1::3] = 0.0
+            t = np.quantile(np.abs(acc), 0.9)
+            acc[0, :] = np.where(np.arange(blk) < budget - 1, 2 * t, 0.0)
+            acc = jnp.asarray(acc)
+            v, i, cnt, res = compact_blocks(acc, jnp.float32(t),
+                                            budget=budget, interpret=True)
+            assert (np.asarray(cnt)[1::3] == 0).all()
+            assert 0 < int(cnt[0]) < budget
+            vals.append(v)
+            idx.append(i)
+            shipped.append(acc - res)
+        return jnp.stack(vals), jnp.stack(idx), jnp.stack(shipped)
+
+    @pytest.mark.parametrize("nb,blk,budget", EXPAND_SHAPES)
+    def test_dense_update_is_the_scatter_bitwise(self, nb, blk, budget):
+        """One pod, p = 0, eta_g = −1: the output is the dense update,
+        bitwise zeros.at[indices].add(values)."""
+        from repro.kernels.compact_topk import expand_blocks
+        vals, idx, _ = self._payload(1, nb, blk, budget, nb + budget)
+        p = jnp.zeros((nb, blk), jnp.float32)
+        got = expand_blocks(p, vals, idx, eta_g=-1.0, n_pods=1,
+                            interpret=True)
+        want = ref.ref_expand_blocks(p, vals, idx, -1.0, 1)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize("nb,blk,budget", EXPAND_SHAPES)
+    def test_round_trip_from_the_pack(self, nb, blk, budget):
+        """expand_blocks of compact_blocks' payload is acc − residual."""
+        from repro.kernels.compact_topk import expand_blocks
+        vals, idx, shipped = self._payload(1, nb, blk, budget, 7 * nb)
+        got = expand_blocks(jnp.zeros((nb, blk), jnp.float32), vals, idx,
+                            eta_g=-1.0, n_pods=1, interpret=True)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(shipped[0]))
+
+    @pytest.mark.parametrize("n_pods", [1, 2, 4])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_pods_mean_applied_to_params(self, n_pods, dtype):
+        """p − eta_g · mean over pods of the dense updates, in p's dtype.
+        The pods sum in pod order and the oracle's scatter in its own, so
+        the two agree to the last bits."""
+        from repro.kernels.compact_topk import expand_blocks
+        nb, blk, budget = 260, 256, 9
+        vals, idx, shipped = self._payload(n_pods, nb, blk, budget, n_pods)
+        p = jnp.asarray(np.random.RandomState(3).randn(nb, blk)
+                        .astype(np.float32)).astype(dtype)
+        got = expand_blocks(p, vals, idx, eta_g=0.7, n_pods=n_pods,
+                            interpret=True)
+        want = ref.ref_expand_blocks(p, vals, idx, 0.7, n_pods)
+        assert got.dtype == dtype and got.shape == p.shape
+        g32 = np.asarray(got.astype(jnp.float32))
+        w32 = np.asarray(want.astype(jnp.float32))
+        tol = 1e-2 if dtype == jnp.bfloat16 else 1e-6
+        np.testing.assert_allclose(g32, w32, rtol=tol, atol=tol)
+        mean = np.asarray(shipped).mean(axis=0)
+        np.testing.assert_allclose(
+            g32, np.asarray(p.astype(jnp.float32)) - 0.7 * mean,
+            rtol=10 * tol, atol=10 * tol)
+
+
 class TestFusedMomentum:
     @pytest.mark.parametrize("d", SHAPES)
     @pytest.mark.parametrize("dtype", DTYPES)

@@ -10,12 +10,14 @@ and the pod-sync block of 1024 at δ = 0.05.
 """
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 ROOT = os.path.join(os.path.dirname(__file__), "..")
@@ -47,13 +49,20 @@ def one_chip():
 
 def _cases(sharding):
     from repro.kernels import ops
-    from repro.kernels.compact_topk import compact_blocks
+    from repro.kernels.compact_topk import compact_blocks, expand_blocks
     from repro.kernels.ef_topk import ef_topk
     from repro.kernels.fused_momentum import fused_momentum
     from repro.kernels.magnitude_hist import magnitude_hist
 
     def f32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+    def expand(n_pods):
+        return (lambda p, v, i: expand_blocks(p, v, i, eta_g=1.0,
+                                              n_pods=n_pods),
+                (f32(NB, BLK), f32(n_pods, NB, BUDGET),
+                 jax.ShapeDtypeStruct((n_pods, NB, BUDGET), jnp.int32,
+                                      sharding=sharding)))
 
     return {
         "magnitude_hist": (lambda g, e: magnitude_hist(g, e),
@@ -66,16 +75,43 @@ def _cases(sharding):
                            (f32(D), f32(D), f32(D))),
         "compact_shard_topk": (lambda a: ops.compact_shard_topk(
             a, budget=BUDGET, interpret=False), (f32(NB, BLK),)),
+        "expand_blocks": expand(1),
+        "expand_blocks_4_pods": expand(4),
     }
 
 
 @pytest.mark.parametrize("name", ["magnitude_hist", "ef_topk",
                                   "compact_blocks", "fused_momentum",
-                                  "compact_shard_topk"])
+                                  "compact_shard_topk", "expand_blocks",
+                                  "expand_blocks_4_pods"])
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, shapes = _cases(one_chip)[name]
     text = jax.jit(fn).lower(*shapes).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def test_compact_pod_sync_applies_without_scatter_or_sort(one_chip):
+    """The compact sync, compiled for one pod on the described chip, applies
+    its payload in the `expand_blocks` kernel: its HLO holds no scatter and
+    no sort. (A CPU build lowers interpret-mode Pallas to XLA ops of its
+    own, so only the chip's compile can show this.)"""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.dist.collectives import make_pod_sync
+    nb = 64
+    mesh = Mesh(np.array(list(one_chip.device_set)), ("pod",))
+    sync = make_pod_sync(mesh, nb * BLK, rate=0.05, n_blocks=nb,
+                         wire="compact", interpret=False)
+
+    def f32(shape, spec):
+        return jax.ShapeDtypeStruct(shape, jnp.float32,
+                                    sharding=NamedSharding(mesh, spec))
+
+    text = jax.jit(sync).lower(f32((nb, BLK), P()),
+                               f32((1, nb, BLK), P("pod")),
+                               f32((1, nb, BLK), P("pod"))).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert not re.findall(r"\s(scatter|sort)\(", text)
 
 
 # ------------------------------------------------------ chip entry point
