@@ -35,16 +35,29 @@ class ArchConfig:
     window: int = 1024
     causal: bool = True
     rope_theta: float = 10_000.0
+    use_rope: bool = True           # False: attention without positions
     mlp_type: str = "gated"         # gated (SiLU) | gelu | none
     norm_type: str = "rms"          # rms | layer
+    norm_eps: float = 1e-6          # RMSNorm eps (block, final and SSM norms)
+    # one-mixer layers in a published order: "M" Mamba-2, "E" MoE,
+    # "*" attention, e.g. "EMEMEM*". Empty: every layer holds every
+    # component of its family (block_init).
+    layer_pattern: str = ""
     # MoE
-    n_experts: int = 0
+    n_experts: int = 0              # experts the router scores
     moe_top_k: int = 0
     capacity_factor: float = 1.25
+    held_experts: int = 0           # pattern "E" layers: experts [0, held)
+                                    # held here (the chip's share)
+    shared_d_ff: int = 0            # pattern "E" layers: shared expert width
+    routed_scale: float = 1.0       # pattern "E" layers: routed_scaling_factor
     # SSM (mamba2 / hymba)
     ssm_state: int = 0
     ssm_head_dim: int = 64
     ssm_expand: int = 2
+    ssm_n_heads: int = 0            # 0 -> ssm_expand * d_model // ssm_head_dim
+    ssm_groups: int = 1             # B/C groups (each serves n_heads/groups)
+    ssm_chunk: int = 256
     conv_width: int = 4
     parallel_ssm: bool = False      # hymba: attention + SSM heads in parallel
     # IO frontend
@@ -67,7 +80,8 @@ class ArchConfig:
 
     @property
     def ssm_heads(self) -> int:
-        return (self.ssm_expand * self.d_model) // self.ssm_head_dim
+        return self.ssm_n_heads or \
+            (self.ssm_expand * self.d_model) // self.ssm_head_dim
 
     @property
     def has_attention(self) -> bool:

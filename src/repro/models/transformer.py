@@ -10,6 +10,11 @@ jax.lax.scan over stacked [L, ...] params keeps the HLO O(1) in depth):
   audio  : non-causal attn + MLP encoder       (hubert)
   vlm    : prefix-LM decoder over [patches; text]  (paligemma)
 
+A config with a `layer_pattern` (Nemotron-H: "M" Mamba-2, "E" expert
+share, "*" attention) instead holds one stack per kind of one-mixer layer,
+[n_kind, ...] each, and scans over whole periods of the pattern, running
+one period's layers in their published order inside the scan body.
+
 Mixed local/global attention (gemma3's 5:1, hymba's 3 full layers) is
 handled INSIDE the scan with a per-layer dynamic window scalar — sliding-
 window layers get w, full layers get S+1 — so the layer pytree stays
@@ -67,8 +72,8 @@ def _norm_init(cfg, d):
 
 
 def _norm_apply(cfg, p, x):
-    return nn.rmsnorm_apply(p, x) if cfg.norm_type == "rms" \
-        else nn.layernorm_apply(p, x)
+    return nn.rmsnorm_apply(p, x, eps=cfg.norm_eps) \
+        if cfg.norm_type == "rms" else nn.layernorm_apply(p, x)
 
 
 # ------------------------------------------------------------------ block init
@@ -116,14 +121,19 @@ def _attn_full(cfg, p, x, window, *, positions, dtype, prefix_len=0):
     B, S, _ = x.shape
     hd = cfg.head_dim_
     h = _norm_apply(cfg, p["attn_norm"], x)
-    q = nn.linear_apply(p["wq"], h, dtype=dtype).reshape(B, S, cfg.n_heads, hd)
-    k = nn.linear_apply(p["wk"], h, dtype=dtype).reshape(B, S, cfg.n_kv_heads, hd)
-    v = nn.linear_apply(p["wv"], h, dtype=dtype).reshape(B, S, cfg.n_kv_heads, hd)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
-    o = flash_attention(q, k, v, causal=cfg.causal, window=window,
-                        prefix_len=prefix_len)
-    out = nn.linear_apply(p["wo"], o.reshape(B, S, -1), dtype=dtype)
+    with jax.named_scope("attn"):
+        q = nn.linear_apply(p["wq"], h, dtype=dtype).reshape(B, S, cfg.n_heads,
+                                                             hd)
+        k = nn.linear_apply(p["wk"], h, dtype=dtype).reshape(B, S,
+                                                             cfg.n_kv_heads, hd)
+        v = nn.linear_apply(p["wv"], h, dtype=dtype).reshape(B, S,
+                                                             cfg.n_kv_heads, hd)
+        if cfg.use_rope:
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
+        o = flash_attention(q, k, v, causal=cfg.causal, window=window,
+                            prefix_len=prefix_len)
+        out = nn.linear_apply(p["wo"], o.reshape(B, S, -1), dtype=dtype)
     return out, (k, v)
 
 
@@ -224,7 +234,8 @@ def block_train(cfg: ArchConfig, p, x, window, *, positions, dtype,
             cache.update(ssm=st, conv=cv)
         else:
             s_out = m2.mamba2_train(p["ssm"], m2.spec_from_cfg(cfg), s_in,
-                                    dtype=dtype)
+                                    chunk=cfg.ssm_chunk, dtype=dtype,
+                                    eps=cfg.norm_eps)
         x = x + s_out
     else:
         a_out, kv = _attn_full(cfg, p, x, window, positions=positions,
@@ -268,6 +279,57 @@ def block_decode(cfg: ArchConfig, p, x, cache, cur_index, window, *, dtype,
     if f is not None:
         x = x + f
     return x, new_cache
+
+
+# ------------------------------------------------------- one-mixer layer stacks
+KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
+
+
+def pattern_period(pattern: str) -> str:
+    """The shortest string whose repeats make up `pattern`."""
+    n = len(pattern)
+    for p in range(1, n + 1):
+        if n % p == 0 and pattern[:p] * (n // p) == pattern:
+            return pattern[:p]
+    raise ValueError("empty layer pattern")
+
+
+def mixer_init(key, cfg: ArchConfig, kind: str, *, param_dtype=jnp.float32):
+    """One layer of a `layer_pattern` model: a pre-norm and one mixer."""
+    d, hd = cfg.d_model, cfg.head_dim_
+    if kind == "mamba":
+        return {"ssm_norm": _norm_init(cfg, d),
+                "ssm": m2.mamba2_init(key, m2.spec_from_cfg(cfg),
+                                      param_dtype=param_dtype)}
+    if kind == "moe":
+        return {"ffn_norm": _norm_init(cfg, d),
+                "moe": moe_lib.share_init(
+                    key, d, cfg.d_ff, cfg.shared_d_ff, cfg.n_experts,
+                    cfg.held_experts or cfg.n_experts,
+                    param_dtype=param_dtype)}
+    ks = jax.random.split(key, 4)
+    lin = functools.partial(nn.linear_init, use_bias=False,
+                            param_dtype=param_dtype)
+    return {"attn_norm": _norm_init(cfg, d),
+            "wq": lin(ks[0], d, cfg.n_heads * hd),
+            "wk": lin(ks[1], d, cfg.n_kv_heads * hd),
+            "wv": lin(ks[2], d, cfg.n_kv_heads * hd),
+            "wo": lin(ks[3], cfg.n_heads * hd, d)}
+
+
+def mixer_apply(cfg: ArchConfig, kind: str, p, x, *, positions, dtype):
+    """x + mixer(norm(x)) for one layer of a `layer_pattern` model."""
+    if kind == "mamba":
+        return x + m2.mamba2_train(
+            p["ssm"], m2.spec_from_cfg(cfg),
+            _norm_apply(cfg, p["ssm_norm"], x), chunk=cfg.ssm_chunk,
+            dtype=dtype, eps=cfg.norm_eps)
+    if kind == "moe":
+        return x + moe_lib.share_apply(
+            p["moe"], _norm_apply(cfg, p["ffn_norm"], x),
+            top_k=cfg.moe_top_k, scale=cfg.routed_scale, dtype=dtype)
+    return x + _attn_full(cfg, p, x, None, positions=positions,
+                          dtype=dtype)[0]
 
 
 # -------------------------------------------------------------------- LM model
@@ -334,6 +396,20 @@ class LM:
         if cfg.frontend == "patches":
             params["patch_proj"] = nn.linear_init(k_fe, cfg.patch_dim,
                                                   cfg.d_model, param_dtype=pd)
+        if cfg.frontend == "tokens" and not cfg.tie_embeddings:
+            params["head"] = nn.linear_init(k_head, cfg.d_model, cfg.vocab,
+                                            use_bias=False, param_dtype=pd)
+        if cfg.layer_pattern:
+            params["layers"] = {}
+            for i, (ch, kind) in enumerate(KINDS.items()):
+                n = cfg.layer_pattern.count(ch)
+                if n:
+                    keys = jax.random.split(jax.random.fold_in(k_layers, i), n)
+                    params["layers"][kind] = jax.vmap(
+                        lambda k, kind=kind: mixer_init(
+                            k, cfg, kind, param_dtype=pd))(keys)
+            params["final_norm"] = _norm_init(cfg, cfg.d_model)
+            return params
         layer_keys = jax.random.split(k_layers, cfg.n_layers)
         params["layers"] = jax.vmap(
             lambda k: block_init(k, cfg, param_dtype=pd))(layer_keys)
@@ -346,9 +422,46 @@ class LM:
         return jnp.asarray([cfg.window if k == "sw" else FULL_WINDOW
                             for k in cfg.layer_kinds()], jnp.int32)
 
+    def _pattern_stack(self, params, x, *, positions):
+        """Scan over whole periods of `layer_pattern`; inside the body the
+        period's layers run in order, each rematerialised on its own."""
+        cfg = self.cfg
+        period = pattern_period(cfg.layer_pattern)
+        reps = len(cfg.layer_pattern) // len(period)
+        stacks = {kind: jax.tree.map(
+            lambda a: a.reshape((reps, a.shape[0] // reps) + a.shape[1:]), st)
+            for kind, st in params["layers"].items()}
+
+        def layer(kind, lp, h):
+            h = self._constrain(h)
+            return mixer_apply(cfg, kind, lp, h, positions=positions,
+                               dtype=self.dtype)
+
+        if self.remat:
+            layer = jax.checkpoint(
+                layer, static_argnums=(0,),
+                policy=jax.checkpoint_policies.nothing_saveable)
+
+        def body(h, lps):
+            seen = dict.fromkeys(lps, 0)
+            for ch in period:
+                kind = KINDS[ch]
+                lp = jax.tree.map(lambda a, i=seen[kind]: a[i], lps[kind])
+                seen[kind] += 1
+                h = layer(kind, lp, h)
+            return self._constrain(h), None
+
+        x, _ = jax.lax.scan(body, x, stacks)
+        return _norm_apply(cfg, params["final_norm"], x), None
+
     def _stack(self, params, x, *, positions, prefix_len=0,
                collect_cache=False):
         cfg = self.cfg
+        if cfg.layer_pattern:
+            if collect_cache or prefix_len:
+                raise NotImplementedError(
+                    "layer_pattern models run training steps only")
+            return self._pattern_stack(params, x, positions=positions)
         windows = self._windows(x.shape[1])
 
         def body(h, xs):
@@ -416,7 +529,9 @@ class LM:
         if cfg.frontend == "patches":      # loss on text positions only
             h = h[:, cfg.n_patches:, :]
         # next-token LM loss, chunked over sequence
-        return chunked_ce_loss(h, params["embed"]["embedding"], labels)
+        head = (params["head"]["kernel"].T if "head" in params
+                else params["embed"]["embedding"])
+        return chunked_ce_loss(h, head, labels)
 
     # --------------------------------------------------------------- prefill
     def prefill(self, params, batch):

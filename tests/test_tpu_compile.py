@@ -114,6 +114,45 @@ def test_compact_pod_sync_applies_without_scatter_or_sort(one_chip):
     assert not re.findall(r"\s(scatter|sort)\(", text)
 
 
+def test_moe_router_dot_is_highest_on_v5e(one_chip):
+    """The expert-share MoE layer at Nemotron 3 Nano's widths (hidden 2688,
+    128 experts scored, top-6, 8 held of width 1856, a shared expert of
+    3712) on 1,024 tokens, compiled with its gradient for the described
+    chip: every dot of the router, forward and backward, carries HIGHEST
+    precision; the shared expert's forward dots carry HIGH and its
+    gradients' the default. At the default precision the chip would round
+    the router's float32 inputs to bfloat16; a CPU build computes the same
+    dot exactly, so only the chip's compile can show this. The held experts
+    run in Pallas grouped-matmul kernels."""
+    from repro.models import moe
+
+    T, d, E = 1024, 2688, 128
+    shapes = jax.eval_shape(
+        lambda: moe.share_init(jax.random.PRNGKey(0), d, 1856, 3712, E, 8))
+
+    def loss(p, x):
+        return jnp.sum(moe.share_apply(p, x, top_k=6, scale=2.5,
+                                       dtype=jnp.float32, interpret=False))
+
+    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip),
+        (shapes, jax.ShapeDtypeStruct((1, T, d), jnp.float32)))
+    text = jax.jit(jax.grad(loss, (0, 1))).lower(*args).compile().as_text()
+    dots = [line for line in text.splitlines()
+            if re.search(r"\s(dot|convolution)\(", line)]
+    router = [line for line in dots if "moe.route" in line]
+    shared = [line for line in dots if "moe.shared" in line]
+    assert router and shared
+    highest = "operand_precision={highest,highest}"
+    assert all(highest in line for line in router), router
+    shared_fwd = [line for line in shared if "transpose(" not in line]
+    shared_bwd = [line for line in shared if "transpose(" in line]
+    assert shared_fwd and shared_bwd
+    assert all("operand_precision={high,high}" in line for line in shared_fwd)
+    assert not any("operand_precision" in line for line in shared_bwd)
+    assert "tpu_custom_call" in text and "ragged-dot" not in text
+
+
 # ------------------------------------------------------ chip entry point
 def _run_smoke(cwd):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
