@@ -3,8 +3,8 @@ and its inverse, the apply of P pods' payloads to the blocked parameters.
 
 Given a blocked EF accumulator [n_blocks, blk] and a threshold t (from the
 magnitude-histogram pipeline), each grid step packs the survivors
-(|acc| >= t, in index order) of ROWS blocks into a fixed `budget` of slots
-per block and emits the pod-sync wire payload:
+(|acc| >= t, in index order) of ROWS = 128 blocks into a fixed `budget` of
+slots per block and emits the pod-sync wire payload:
 
     values   f32[n_blocks, budget]   front-packed kept entries
     indices  i32[n_blocks, budget]   shard-local flat coordinates
@@ -17,27 +17,35 @@ survivors than `budget` truncate in index order; the overflow stays in the
 residual and ships next round (bounded deferral — the same EF contract the
 threshold pipeline already relies on).
 
-The pack is sort-free. Each survivor's output slot is the exclusive prefix
-count of the keep mask, computed per 128-lane tile as a matmul with a
-strictly-upper-triangular ones matrix plus a running per-row carry (the
-TPU compiler has no cumsum; 0/1 operands and sums <= blk are exact in any
-matmul precision). A one-hot [budget, blk] matrix built from those slots
-lowers the gather to MXU `dot_general`s at HIGHEST precision: each output
-slot is one survivor times 1.0 plus zeros, so the values are bitwise exact,
-and so are the in-block offsets (< 2^24), which become int32 shard-flat
-indices by integer adds.
+Both kernels work slot-major: one block a lane, 128 blocks a grid step
+(the last one partial, its lanes past n_blocks dropped on write), so that
+slot j of every block is one sublane row. The pack transposes its
+[128 blocks, blk] tile in VMEM so that offsets run down the sublanes. A
+survivor's slot is the exclusive prefix count of the keep mask down the
+sublanes, per 128 offsets a matmul with a strictly-lower-triangular ones
+matrix plus a running per-block carry (the TPU compiler has no cumsum).
+Then, offset by offset, the slot row is compared with the sublane iota of
+the [budget, 128] payload tile, and where it hits, the value and the
+offset are selected in. The payload is transposed back, the offsets
+become int32 shard-flat indices by integer adds, and the residual is
+written in row layout.
+
+No MXU precision bears on the payload. The one matmul is the prefix
+count, whose 0/1 bfloat16 operands and sums <= blk are exact in f32 at
+the default precision. Values and offsets are moved by selects, never
+multiplied, so each live slot holds its survivor bitwise (an ±Inf too),
+and a NaN or Inf in a block leaves its other slots alone.
 
 `expand_blocks` inverts the pack without a sort or a scatter: block b's
-slots sit in row b, so each grid step rebuilds its EXPAND_ROWS = 128
-blocks from their own rows of the P payloads. It transposes them
-slot-major, one block per lane, so that slot j of every block is one
-sublane row; that row, broadcast down the sublanes, is compared with the
-sublane iota of a [128 offsets, 128 blocks] tile of the transposed dense
-update, selected and added in, for every slot and chunk of offsets, on the
-vector unit. The update is transposed back and applied in the same pass.
-(Taking slot j out of a [ROWS, budget] row-major tile instead needs a
-cross-lane reduction per slot, whose latency made that form as slow as
-the scatter-add on a v5e.)
+slots sit in row b, so each grid step rebuilds its ROWS blocks from their
+own rows of the P payloads. It transposes them slot-major; slot j's row,
+broadcast down the sublanes, is compared with the sublane iota of a
+[128 offsets, 128 blocks] tile of the transposed dense update, selected
+and added in, for every slot and chunk of offsets, on the vector unit.
+The update is transposed back and applied in the same pass. (Taking
+slot j out of a [8, budget] row-major tile instead needs a cross-lane
+reduction per slot, whose latency made that form as slow as the
+scatter-add on a v5e.)
 """
 from __future__ import annotations
 
@@ -50,56 +58,75 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import tiling
 
-ROWS = tiling.ROWS   # blocks per grid step (the f32 sublane tile)
-EXPAND_ROWS = tiling.LANES   # blocks per expand_blocks step: one a lane
-_NT = (((1,), (1,)), ((), ()))   # contract lanes of both operands: A · Bᵀ
-_EXACT = dict(precision=jax.lax.Precision.HIGHEST,
-              preferred_element_type=jnp.float32)
+ROWS = tiling.LANES   # blocks per grid step of either kernel: one a lane
 
 
-def _exclusive_prefix(keep: jax.Array) -> jax.Array:
-    """Per-row exclusive prefix sum along lanes of a 0/1 [rows, blk] mask."""
-    rows, blk = keep.shape
-    tile = tiling.LANES if blk % tiling.LANES == 0 else blk
-    iota = functools.partial(jax.lax.broadcasted_iota, jnp.int32, (tile, tile))
-    upper = (iota(0) < iota(1)).astype(jnp.float32)
-    carry = jnp.zeros((rows, 1), jnp.float32)
+def _slots_t(acc_t: jax.Array, threshold, budget: int) -> jax.Array:
+    """Output slot of each survivor of an offsets-by-blocks [blk, rows]
+    tile, or -1 where the entry does not ship: the exclusive prefix count
+    of the keep mask down the sublanes, per chunk of offsets a matmul with
+    a strictly-lower-triangular ones matrix plus a running per-block carry
+    (the TPU compiler has no cumsum; 0/1 bfloat16 operands summed in f32
+    are exact at the default precision)."""
+    blk, rows = acc_t.shape
+    chunk = tiling.LANES if blk % tiling.LANES == 0 else blk
+    iota = functools.partial(jax.lax.broadcasted_iota, jnp.int32,
+                             (chunk, chunk))
+    lower = (iota(0) > iota(1)).astype(jnp.bfloat16)
+    carry = jnp.zeros((1, rows), jnp.float32)
     parts = []
-    for c in range(0, blk, tile):
-        kt = keep[:, c:c + tile]
-        parts.append(jnp.dot(kt, upper, preferred_element_type=jnp.float32)
-                     + carry)
-        carry = carry + jnp.sum(kt, axis=1, keepdims=True)
-    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+    for c in range(0, blk, chunk):
+        keep = jnp.abs(acc_t[c:c + chunk]) >= threshold
+        kf = keep.astype(jnp.float32)
+        pos = jnp.dot(lower, kf.astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32) + carry
+        parts.append(jnp.where(keep & (pos < budget), pos.astype(jnp.int32),
+                               -1))
+        carry = carry + jnp.sum(kf, axis=0, keepdims=True)
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
 
 
-def _compact_kernel(acc_ref, t_ref, vals_ref, idx_ref, cnt_ref, res_ref, *,
-                    budget: int):
+def _gather(acc_t, slot_t, budget: int):
+    """Slot-major payload [budget, rows] of one grid step: for each offset
+    i, the slots that row i's survivors fill take its value and i by a
+    compare and two selects. A slot is filled by at most one offset, so
+    each live slot holds its survivor bitwise and a dead one (0.0, 0)."""
+    blk, rows = acc_t.shape
+    slots = jax.lax.broadcasted_iota(jnp.int32, (budget, rows), 0)
+    step = tiling.ROWS if blk % tiling.ROWS == 0 else 1
+
+    def take_offsets(g, carry):              # offsets [i0, i0 + step)
+        vals, offs = carry
+        i0 = pl.multiple_of(g * step, step)
+        for r in range(step):
+            hit = slots == slot_t[pl.ds(i0 + r, 1), :]
+            vals = jnp.where(hit, acc_t[pl.ds(i0 + r, 1), :], vals)
+            offs = jnp.where(hit, i0 + r, offs)
+        return vals, offs
+
+    return jax.lax.fori_loop(
+        0, blk // step, take_offsets,
+        (jnp.zeros((budget, rows), jnp.float32),
+         jnp.zeros((budget, rows), jnp.int32)))
+
+
+def _compact_kernel(acc_ref, t_ref, vals_ref, idx_ref, cnt_ref, res_ref,
+                    acc_t, slot_t, *, budget: int):
     rows, blk = acc_ref.shape
     acc = acc_ref[...]
-    keep = jnp.where(jnp.abs(acc) >= t_ref[0], 1.0, 0.0)
-    pos = _exclusive_prefix(keep)                # output slot per survivor
-    ship = jnp.where(pos < budget, keep, 0.0)
-    cnt = jnp.sum(ship, axis=1, keepdims=True).astype(jnp.int32)  # [rows, 1]
+    acc_t[...] = acc.T                           # offsets down the sublanes
+    slot_t[...] = _slots_t(acc_t[...], t_ref[0], budget)
+    cnt = jnp.sum((slot_t[...] >= 0).astype(jnp.int32), axis=0,
+                  keepdims=True)                 # [1, rows]
     cnt_ref[...] = cnt
-    res_ref[...] = acc - jnp.where(ship > 0, acc, 0.0)
+    res_ref[...] = acc - jnp.where(slot_t[...].T >= 0, acc, 0.0)
 
-    # integer iotas (the TPU compiler has no float iota), compared as f32
-    slot = jax.lax.broadcasted_iota(jnp.int32, (budget, blk), 0) \
-        .astype(jnp.float32)
-    offset = jax.lax.broadcasted_iota(jnp.int32, (1, blk), 1) \
-        .astype(jnp.float32)
-    live = jax.lax.broadcasted_iota(jnp.int32, (1, budget), 1)
-    base = pl.program_id(0) * (rows * blk)
-    for r in range(rows):
-        onehot = jnp.where((slot == pos[r:r + 1]) & (ship[r:r + 1] > 0),
-                           1.0, 0.0)                     # [budget, blk]
-        vals_ref[r:r + 1, :] = jax.lax.dot_general(acc[r:r + 1], onehot, _NT,
-                                                   **_EXACT)
-        off = jax.lax.dot_general(offset, onehot, _NT, **_EXACT)
-        idx_ref[r:r + 1, :] = jnp.where(live < cnt[r:r + 1],
-                                        base + r * blk + off.astype(jnp.int32),
-                                        0)
+    vals, offs = _gather(acc_t, slot_t, budget)
+    block = pl.program_id(0) * rows \
+        + jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
+    live = jax.lax.broadcasted_iota(jnp.int32, (budget, rows), 0) < cnt
+    vals_ref[...] = vals.T
+    idx_ref[...] = jnp.where(live, block * blk + offs, 0).T
 
 
 @functools.partial(jax.jit, static_argnames=("budget", "interpret"))
@@ -114,31 +141,27 @@ def compact_blocks(acc: jax.Array, threshold: jax.Array, *, budget: int,
     n_blocks, blk = acc.shape
     if not 1 <= budget <= blk:
         raise ValueError(f"budget={budget} outside [1, blk={blk}]")
-    acc = acc.astype(jnp.float32)
-    pad = (-n_blocks) % ROWS
-    if pad:
-        acc = jnp.concatenate([acc, jnp.zeros((pad, blk), jnp.float32)])
-    n_pad = acc.shape[0]
 
     def rows_of(width):
         return pl.BlockSpec((ROWS, width), lambda i: (i, 0))
 
     vals, idx, cnt, res = pl.pallas_call(
         functools.partial(_compact_kernel, budget=budget),
-        grid=(n_pad // ROWS,),
+        grid=(pl.cdiv(n_blocks, ROWS),),
         in_specs=[rows_of(blk), tiling.scalar_spec()],
-        out_specs=[rows_of(budget), rows_of(budget), rows_of(1),
-                   rows_of(blk)],
+        out_specs=[rows_of(budget), rows_of(budget),
+                   pl.BlockSpec((1, ROWS), lambda i: (0, i)), rows_of(blk)],
         out_shape=[
-            jax.ShapeDtypeStruct((n_pad, budget), jnp.float32),
-            jax.ShapeDtypeStruct((n_pad, budget), jnp.int32),
-            jax.ShapeDtypeStruct((n_pad, 1), jnp.int32),
-            jax.ShapeDtypeStruct((n_pad, blk), jnp.float32),
+            jax.ShapeDtypeStruct((n_blocks, budget), jnp.float32),
+            jax.ShapeDtypeStruct((n_blocks, budget), jnp.int32),
+            jax.ShapeDtypeStruct((1, n_blocks), jnp.int32),
+            jax.ShapeDtypeStruct((n_blocks, blk), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((blk, ROWS), jnp.float32),
+                        pltpu.VMEM((blk, ROWS), jnp.int32)],
         interpret=interpret,
-    )(acc, tiling.scalar(threshold))
-    return (vals[:n_blocks], idx[:n_blocks], cnt[:n_blocks, 0],
-            res[:n_blocks])
+    )(acc.astype(jnp.float32), tiling.scalar(threshold))
+    return vals, idx, cnt[0], res
 
 
 def _expand_kernel(p_ref, vals_ref, idx_ref, eta_ref, inv_n_ref, out_ref,
@@ -190,7 +213,7 @@ def expand_blocks(p: jax.Array, values: jax.Array, indices: jax.Array, *,
     """
     n_blocks, blk = p.shape
     n_payloads, _, budget = values.shape
-    rows = EXPAND_ROWS
+    rows = ROWS
     payload = pl.BlockSpec((n_payloads, rows, budget), lambda i: (0, i, 0))
     tile = pl.BlockSpec((rows, blk), lambda i: (i, 0))
     return pl.pallas_call(

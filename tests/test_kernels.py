@@ -130,8 +130,15 @@ class TestCompactBlocks:
         return jnp.asarray(rng.randn(nb, blk).astype(np.float32)
                            * np.exp(rng.randn(nb, blk).astype(np.float32)))
 
-    @pytest.mark.parametrize("nb,blk", [(1, 128), (8, 64), (12, 256)])
-    @pytest.mark.parametrize("budget", [1, 5, 32])
+    # every budget over three shapes inside one grid step; then three steps
+    # of 128 blocks at the cells' width and budget, the last one partial;
+    # two steps, the second holding one block; budget == blk on a chunk
+    # narrower than 128 offsets
+    @pytest.mark.parametrize(
+        "budget,nb,blk",
+        [(b, nb, blk) for b in (1, 5, 32)
+         for nb, blk in ((1, 128), (8, 64), (12, 256))]
+        + [(51, 300, 1024), (32, 129, 256), (64, 5, 64)])
     def test_vs_oracle_bitwise(self, nb, blk, budget):
         from repro.kernels.compact_topk import compact_blocks
         acc = self._acc(nb, blk, nb * blk + budget)
@@ -141,6 +148,32 @@ class TestCompactBlocks:
         for g_, w_, name in zip(got, want, ("vals", "idx", "cnt", "res")):
             np.testing.assert_array_equal(np.asarray(g_), np.asarray(w_),
                                           err_msg=name)
+
+    def test_nonfinite_entries_match_oracle(self):
+        """NaN and ±Inf in a block leave its payload the oracle's: ±Inf
+        (|x| >= t) ship in their own slots and leave NaN (Inf − Inf) in the
+        residual; a NaN compares below any threshold, so it stays in the
+        residual and no slot of its block turns NaN (a one-hot matmul
+        makes 0 · Inf = NaN in every slot of the row)."""
+        from repro.kernels.compact_topk import compact_blocks
+        nb, blk, budget = 3, 128, 8
+        acc = np.asarray(self._acc(nb, blk, 41)).copy()
+        acc[1, [3, 40]] = np.inf, -np.inf
+        acc[1, 17] = np.nan
+        acc[2, 0] = np.nan
+        t = jnp.float32(np.quantile(np.abs(acc[np.isfinite(acc)]), 0.97))
+        got = compact_blocks(jnp.asarray(acc), t, budget=budget,
+                             interpret=True)
+        want = ref.ref_compact_blocks(jnp.asarray(acc), t, budget)
+        for g_, w_, name in zip(got, want, ("vals", "idx", "cnt", "res")):
+            np.testing.assert_array_equal(np.asarray(g_), np.asarray(w_),
+                                          err_msg=name)
+        vals, idx, cnt, res = map(np.asarray, got)
+        assert not np.isnan(vals).any()
+        assert set(vals[1][np.isinf(vals[1])]) == {np.inf, -np.inf}
+        assert {128 + 3, 128 + 40} <= set(idx[1][:cnt[1]])
+        assert 128 + 17 not in set(idx[1][:cnt[1]])
+        assert np.isnan(res[1, [3, 17, 40]]).all() and np.isnan(res[2, 0])
 
     @pytest.mark.parametrize("threshold,expect", [(0.0, "all"),
                                                   (np.inf, "none")])

@@ -24,6 +24,7 @@ ROOT = os.path.join(os.path.dirname(__file__), "..")
 D = 1_663_370            # cnn_fmnist flat dim
 BLK, BUDGET = 1024, 51   # pod-sync block and its δ = 0.05 budget
 NB = -(-D // BLK)
+NB_MAMBA = 361_456       # the mamba2-780m cell's blocks: 2,824 steps of 128
 
 
 @pytest.fixture(scope="module")
@@ -71,6 +72,9 @@ def _cases(sharding):
                     (f32(D), f32(D), f32())),
         "compact_blocks": (lambda a, t: compact_blocks(a, t, budget=BUDGET),
                            (f32(NB, BLK), f32())),
+        "compact_blocks_mamba_cell": (
+            lambda a, t: compact_blocks(a, t, budget=BUDGET),
+            (f32(NB_MAMBA, BLK), f32())),
         "fused_momentum": (lambda w, m, g: fused_momentum(w, m, g, lr=0.05),
                            (f32(D), f32(D), f32(D))),
         "compact_shard_topk": (lambda a: ops.compact_shard_topk(
@@ -81,7 +85,8 @@ def _cases(sharding):
 
 
 @pytest.mark.parametrize("name", ["magnitude_hist", "ef_topk",
-                                  "compact_blocks", "fused_momentum",
+                                  "compact_blocks",
+                                  "compact_blocks_mamba_cell", "fused_momentum",
                                   "compact_shard_topk", "expand_blocks",
                                   "expand_blocks_4_pods"])
 def test_kernel_compiles_for_v5e(one_chip, name):
